@@ -8,6 +8,8 @@ import argparse
 import sys
 import traceback
 
+from repro.launch.compile_cache import enable_compile_cache
+
 from benchmarks import (async_sim, comm, faults, fig5_partial_training,
                         fig7_vit_finetune, kernel_microbench, obs_overhead,
                         prefix_cache, roofline_report, round_engine, scale,
@@ -37,6 +39,7 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", choices=sorted(BENCHES), default=None)
     args = ap.parse_args()
+    enable_compile_cache()
     failed = []
     for name, fn in BENCHES.items():
         if args.only and name != args.only:

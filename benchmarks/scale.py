@@ -23,7 +23,10 @@ Three questions the scale subsystem must answer with numbers:
 Every row runs in a FRESH subprocess: the forced multi-device mesh
 needs ``XLA_FLAGS`` set before backend init (docs/scale.md §Testing on
 a forced mesh), and ``ru_maxrss`` is per-process — sharing one
-interpreter would let an early fat row mask a later lean one.
+interpreter would let an early fat row mask a later lean one.  The
+children are pinned to ``JAX_PLATFORMS=cpu`` (the forced mesh is a
+host-platform device count), so every time here is a host-CPU time, on
+any machine; each row records the ``device`` it ran on to say so.
 
 Emits ``BENCH_scale.json`` via :func:`bench_lib.write_json`; CI runs
 the quick tier as a smoke (headline off) and uploads the report.  The
@@ -194,6 +197,10 @@ def _child(spec_json: str) -> None:
     from repro.launch.mesh import force_host_device_count
     force_host_device_count(DEVICES)
     out = ROW_KINDS[spec["kind"]](spec)
+    import jax
+    dev = jax.devices()[0]
+    out["device"] = {"platform": dev.platform, "kind": dev.device_kind,
+                     "count": jax.device_count()}
     print(MARK + json.dumps(out))
 
 
@@ -243,7 +250,8 @@ def main() -> None:
         print(f"  [scale] {name} ...", flush=True)
         results[name] = _run_row(name, spec)
         r = results[name]
-        extra = f" rss={r['peak_rss_mb']:.0f}MB"
+        extra = (f" [{r['device']['platform']}]"
+                 f" rss={r['peak_rss_mb']:.0f}MB")
         if "s_per_round" in r:
             spr = r["s_per_round"]
             spr = spr[-1] if isinstance(spr, list) else spr

@@ -53,7 +53,6 @@ from typing import List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.blockwise import (batch_signature, broadcast_tree,
@@ -178,6 +177,9 @@ class ShardedScheduler:
         self.max_lanes = None if max_lanes is None else max(2, int(max_lanes))
         self._mesh = mesh
         self.fallback = VectorizedScheduler(min_group)
+        # device the next group's first chunk goes to: groups narrower
+        # than the mesh would otherwise all land on device 0
+        self._next_device = 0
 
     @property
     def mesh(self):
@@ -236,17 +238,20 @@ class ShardedScheduler:
     def _run_group(self, ctx, strategy, state, gids, gbatches):
         """One group's locals, fanned out chunk-per-device.  Dispatch is
         async — every device's chunk is in flight before the first
-        result is unstacked.  NOTE fn's donate_argnums is harmless here:
-        the donation-gated backends (cpu) donate nothing, and the
-        broadcast input is a fresh buffer per chunk anyway."""
+        result is unstacked.  Chunks take devices round-robin, carried
+        across groups, so a round of small groups still spreads over the
+        mesh.  ``fn`` donates its stacked-params argument; each chunk
+        passes a fresh broadcast buffer that nothing else holds, so
+        donation never invalidates a live array."""
         fn = strategy.group_update_fn(ctx, gids)
         devices = list(self.mesh.devices.flat)
         G = len(gids)
         outs = []
         start = 0
         widths = self._chunk_widths(G, len(devices), self.max_lanes)
-        for i, w in enumerate(widths):
-            dev = devices[i % len(devices)]
+        for w in widths:
+            dev = devices[self._next_device % len(devices)]
+            self._next_device += 1
             chunk = gbatches[start:start + w]
             start += w
             outs.append((w, fn(
@@ -354,8 +359,8 @@ class ShardedScheduler:
                 locals_ = fn(p_stack, b_stack)
                 return psum_masked_partials(locals_, mask_, w_stack)
 
-            cache[key] = jax.jit(shard_map(
-                body, mesh,
+            cache[key] = jax.jit(jax.shard_map(
+                body, mesh=mesh,
                 in_specs=(P("data"), P("data"), P("data"), P()),
                 out_specs=P()))
         spec = NamedSharding(mesh, P("data"))

@@ -9,7 +9,11 @@ matrix never exists in HBM — the live working set is one
 (block_t, block_v) tile in VMEM.
 
 grid = (T/block_t, V/block_v), vocab innermost; scratch carries the
-running max/sum-exp and the gathered gold logit per token row.
+running max/sum-exp and the gathered gold logit per token row.  The
+requested tiles are shrunk (:func:`fit_tiles`) until the double-buffered
+hidden and weight tiles plus the f32 logit temporaries fit v5e's 16 MiB
+default scoped VMEM: at D=1024 the default (1024, 2048) f32 weight tile
+alone would take 16 MiB double-buffered, which the TPU compiler refuses.
 """
 from __future__ import annotations
 
@@ -21,6 +25,31 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+
+# VMEM working-set budget per kernel instance, kept under the 16 MiB
+# default scoped-VMEM limit of TPU v5e with room for Mosaic's own scratch
+VMEM_BUDGET = 12 * 2**20
+
+
+def fit_tiles(BT: int, D: int, V: int, block_t: int, block_v: int,
+              itemsize: int = 4):
+    """Clamp ``(block_t, block_v)`` to the array and halve them (vocab
+    first) until the working set fits :data:`VMEM_BUDGET`: the
+    double-buffered ``(block_t, D)`` hidden and ``(D, block_v)`` weight
+    tiles plus ~4 f32 ``(block_t, block_v)`` logit temporaries.  Halving
+    stops at TPU tiling granules (8 rows, 128 lanes)."""
+    block_t, block_v = min(block_t, BT), min(block_v, V)
+
+    def working_set(bt, bv):
+        return 2 * itemsize * D * (bt + bv) + 4 * 4 * bt * bv
+
+    while working_set(block_t, block_v) > VMEM_BUDGET and \
+            block_v % 256 == 0:
+        block_v //= 2
+    while working_set(block_t, block_v) > VMEM_BUDGET and \
+            block_t % 16 == 0:
+        block_t //= 2
+    return block_t, block_v
 
 
 def _ce_kernel(h_ref, w_ref, lbl_ref, nll_ref, m_ref, l_ref, g_ref, *,
@@ -76,8 +105,9 @@ def chunked_cross_entropy(
     B, T, D = hidden.shape
     V = lm_head.shape[1]
     BT = B * T
-    block_t = min(block_t, BT)
-    block_v = min(block_v, V)
+    block_t, block_v = fit_tiles(BT, D, V, block_t, block_v,
+                                 max(hidden.dtype.itemsize,
+                                     lm_head.dtype.itemsize))
 
     h = hidden.reshape(BT, D)
     lbl = labels.reshape(BT, 1).astype(jnp.int32)

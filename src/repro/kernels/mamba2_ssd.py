@@ -55,12 +55,14 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, h0_ref,
     Cm = jnp.where(valid, Cm, 0.0)
 
     l = A * dt                                   # (Cn,1) <= 0
-    cs = jnp.cumsum(l, axis=0)                   # inclusive cumsum
+    # inclusive cumsum as a masked lane reduction: Mosaic has no cumsum
+    cn = l.shape[0]
+    causal = (jax.lax.broadcasted_iota(jnp.int32, (cn, cn), 0)
+              >= jax.lax.broadcasted_iota(jnp.int32, (cn, cn), 1))
+    cs = jnp.sum(jnp.where(causal, l.T, 0.0), axis=1, keepdims=True)
 
     # intra-chunk "attention" matrix, strictly causal in i<=t
     rel = cs - cs.T                              # (Cn,Cn) cs_t - cs_i
-    causal = (jax.lax.broadcasted_iota(jnp.int32, rel.shape, 0)
-              >= jax.lax.broadcasted_iota(jnp.int32, rel.shape, 1))
     decay = jnp.where(causal, jnp.exp(rel), 0.0)
     scores = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)

@@ -33,29 +33,26 @@ def _rwkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref,
     def _init():
         state_ref[...] = s0_ref[0]
 
-    r = r_ref[0].astype(jnp.float32)        # (C, D)
-    k = k_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)
-    d = jnp.exp(-jnp.exp(w_ref[0].astype(jnp.float32)))
-    u = u_ref[0].astype(jnp.float32)        # (D,)
+    u = u_ref[0].astype(jnp.float32)        # (1, D)
 
-    def step(i, y):
+    # rows are read from and written to the refs one time step at a
+    # time: Mosaic slices refs at a dynamic sublane offset, but not values
+    def step(i, carry):
         t_global = ti * block_t + i
-        r_t = jax.lax.dynamic_slice_in_dim(r, i, 1, 0)      # (1, D)
-        k_t = jax.lax.dynamic_slice_in_dim(k, i, 1, 0)
-        v_t = jax.lax.dynamic_slice_in_dim(v, i, 1, 0)
-        d_t = jax.lax.dynamic_slice_in_dim(d, i, 1, 0)
+        r_t = r_ref[0, pl.ds(i, 1), :].astype(jnp.float32)   # (1, D)
+        k_t = k_ref[0, pl.ds(i, 1), :].astype(jnp.float32)
+        v_t = v_ref[0, pl.ds(i, 1), :].astype(jnp.float32)
+        d_t = jnp.exp(-jnp.exp(w_ref[0, pl.ds(i, 1), :].astype(jnp.float32)))
         S = state_ref[...]                                   # (D, D)
         kv = k_t.T @ v_t                                     # (D, D) outer
-        y_t = r_t @ (S + u[:, None] * kv)                    # (1, D)
+        y_t = r_t @ (S + u.T * kv)                           # (1, D)
         # ragged tail: don't advance state past seq_len
         advance = t_global < seq_len
         state_ref[...] = jnp.where(advance, d_t.T * S + kv, S)
-        return jax.lax.dynamic_update_slice_in_dim(y, y_t, i, 0)
+        y_ref[0, pl.ds(i, 1), :] = y_t.astype(y_ref.dtype)
+        return carry
 
-    y = jax.lax.fori_loop(0, block_t, step,
-                          jnp.zeros((block_t, r.shape[1]), jnp.float32))
-    y_ref[0] = y.astype(y_ref.dtype)
+    jax.lax.fori_loop(0, block_t, step, 0)
 
     @pl.when(ti == nt - 1)
     def _emit_state():
@@ -84,7 +81,9 @@ def rwkv6_scan(
         return jnp.swapaxes(x, 1, 2).reshape(BH, T, D)
 
     rf, kf, vf, wf = map(fold, (r, k, v, w))
-    uf = jnp.broadcast_to(u[None], (B, H, D)).reshape(BH, D)
+    # (BH, 1, D) so the (1, 1, D) block spans the array's last two dims,
+    # as the TPU tiling rule requires of a block narrower than (8, 128)
+    uf = jnp.broadcast_to(u[None], (B, H, D)).reshape(BH, 1, D)
     if initial_state is None:
         initial_state = jnp.zeros((B, H, D, D), jnp.float32)
     s0 = initial_state.reshape(BH, D, D).astype(jnp.float32)
@@ -101,7 +100,7 @@ def rwkv6_scan(
             pl.BlockSpec((1, block_t, D), lambda b, t: (b, t, 0)),
             pl.BlockSpec((1, block_t, D), lambda b, t: (b, t, 0)),
             pl.BlockSpec((1, block_t, D), lambda b, t: (b, t, 0)),
-            pl.BlockSpec((1, D), lambda b, t: (b, 0)),
+            pl.BlockSpec((1, 1, D), lambda b, t: (b, 0, 0)),
             pl.BlockSpec((1, D, D), lambda b, t: (b, 0, 0)),
         ],
         out_specs=[

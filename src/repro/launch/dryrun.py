@@ -103,7 +103,7 @@ def _lower_costing(cfg, shape, mesh, fsdp=None, no_remat=False,
                                       decode_tokens=decode_tokens)
     remat_ctx = model_common.disable_remat() if no_remat \
         else contextlib.nullcontext()
-    with mesh, model_common.unroll_scans(), remat_ctx:
+    with jax.set_mesh(mesh), model_common.unroll_scans(), remat_ctx:
         if shape.mode == "train":
             opt_shape = steps.abstract_opt_state(params_shape)
             jitted = jax.jit(step_fn, in_shardings=(pspecs, pspecs, bspecs),
@@ -220,7 +220,7 @@ def dryrun_one(arch: str, shape_name: str, *, multi_pod: bool = False,
     ws_ctx = model_common.weight_stationary_decode() if ws_decode \
         else contextlib.nullcontext()
     ep_ctx = model_common.ep_moe() if moe_ep else contextlib.nullcontext()
-    with mesh, remat_ctx, ws_ctx, ep_ctx:
+    with jax.set_mesh(mesh), remat_ctx, ws_ctx, ep_ctx:
         if shape.mode == "train":
             if fedepth_block is not None:
                 # momentum exists only for the trained block
@@ -280,7 +280,7 @@ def dryrun_one(arch: str, shape_name: str, *, multi_pod: bool = False,
         from repro.models import common as model_common
         remat_ctx2 = model_common.disable_remat() if no_remat \
             else contextlib.nullcontext()
-        with mesh, model_common.unroll_scans(), remat_ctx2:
+        with jax.set_mesh(mesh), model_common.unroll_scans(), remat_ctx2:
             cost_fn, _ = steps.step_for_shape(
                 lm, shape, fedepth_block=fedepth_block, kernel_force="ref",
                 accum_steps=1, buffered_z=buffered_z)

@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ARCH_IDS, get_config, get_reduced_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build
 from repro.models.api import init_cache
 
@@ -27,6 +28,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--temperature", type=float, default=0.0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_reduced_config(args.arch) if args.reduced else get_config(args.arch)
     if cfg.is_encoder_decoder:
@@ -42,8 +44,7 @@ def main(argv=None):
                                 cfg.vocab_size)
     cache = init_cache(cfg, B, S)
 
-    decode = jax.jit(lambda p, t, c, i: lm.decode_step(
-        p, t, c, i, kernel_force="ref"))
+    decode = jax.jit(lambda p, t, c, i: lm.decode_step(p, t, c, i))
 
     # prefill via sequential decode (cache-consistency is the point here;
     # the production prefill path is lm.prefill + cache download)

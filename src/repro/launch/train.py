@@ -22,6 +22,7 @@ from repro.configs import ARCH_IDS, get_config, get_reduced_config
 from repro.core import decomposition, memory_model
 from repro.data.tokens import TokenPipeline
 from repro.launch import steps as step_lib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import build
 from repro.train import checkpoint
@@ -41,6 +42,7 @@ def main(argv=None):
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_reduced_config(args.arch) if args.reduced else get_config(args.arch)
     lm = build(cfg)
@@ -73,10 +75,9 @@ def main(argv=None):
         block_steps = []
         opt_states = []
         from repro.core import blockwise
-        runner = blockwise.lm_runner(lm, kernel_force="ref")
+        runner = blockwise.lm_runner(lm)
         for (lo, hi) in dec.blocks:
-            fn, _ = step_lib.make_fedepth_block_step(lm, lo, hi, lr=args.lr,
-                                                     kernel_force="ref")
+            fn, _ = step_lib.make_fedepth_block_step(lm, lo, hi, lr=args.lr)
             block_steps.append(jax.jit(fn))
             opt_states.append(None)
         t0 = time.time()
@@ -92,8 +93,7 @@ def main(argv=None):
             print(f"step {s:4d} block[{lo}:{hi}] loss={float(m['loss']):.4f} "
                   f"({time.time() - t0:.1f}s)")
     else:
-        step = jax.jit(step_lib.make_train_step(lm, lr=args.lr,
-                                                kernel_force="ref"))
+        step = jax.jit(step_lib.make_train_step(lm, lr=args.lr))
         opt = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
         t0 = time.time()
         for s in range(args.steps):

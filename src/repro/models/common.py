@@ -110,22 +110,9 @@ def ws_batch_sharded(x, bdim: int = 0):
 
 
 def _context_mesh():
-    """The active mesh: the legacy ``with mesh:`` context (jax<=0.8 does
-    NOT surface it via get_abstract_mesh) or the new set_mesh context."""
-    try:
-        from jax._src import mesh as mesh_lib
-        m = mesh_lib.thread_resources.env.physical_mesh
-        if m is not None and not m.empty:
-            return m
-    except Exception:
-        pass
-    try:
-        m = jax.sharding.get_abstract_mesh()
-        if m is not None and m.axis_names:
-            return m
-    except Exception:
-        pass
-    return None
+    """The mesh set by ``jax.set_mesh``, or ``None`` outside one."""
+    m = jax.sharding.get_abstract_mesh()
+    return m if m.axis_names else None
 
 
 def shard_hint(x, *axes):

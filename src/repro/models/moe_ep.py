@@ -21,13 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-# jax >= 0.7 exposes shard_map at the top level; the pinned 0.4.x line
-# only has the experimental module — resolve whichever exists.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from repro.configs.base import ModelConfig
 from repro.models import common
 
@@ -113,9 +106,6 @@ def forward_ep(p, cfg: ModelConfig, x, mesh, *,
         in_specs=(xspec, P(), P("model", None, None),
                   P("model", None, None), P("model", None, None)),
         out_specs=(xspec, P()))
-    try:   # replication checking: spelled check_vma since jax 0.7,
-        mapped = _shard_map(fn, check_vma=False, **specs)
-    except TypeError:   # check_rep on the 0.4.x experimental API
-        mapped = _shard_map(fn, check_rep=False, **specs)
+    mapped = jax.shard_map(fn, check_vma=False, **specs)
     out = mapped(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
     return out

@@ -1,5 +1,7 @@
 """Shared fixtures.  NOTE: tests run on the default single CPU device —
 never import repro.launch.dryrun here (it forces 512 host devices)."""
+import os
+
 import jax
 import pytest
 
@@ -20,4 +22,4 @@ def multi_device_env():
     jax import touches the backend.  See docs/scale.md §Testing on a
     forced mesh."""
     return {"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-            "HOME": "/root", "JAX_PLATFORMS": "cpu"}
+            "HOME": os.environ.get("HOME", ""), "JAX_PLATFORMS": "cpu"}

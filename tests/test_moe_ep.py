@@ -4,8 +4,10 @@ scatter-dispatch path: exact agreement on a multi-device host mesh.
 NOTE: this file spawns a subprocess so the 8-device XLA_FLAGS never leak
 into the main test process (everything else runs on 1 device).
 """
+import os
 import subprocess
 import sys
+from pathlib import Path
 
 import pytest
 
@@ -23,13 +25,13 @@ p = moe.init(key, cfg)
 x = jax.random.normal(jax.random.fold_in(key, 1), (4, 8, cfg.d_model)) * 0.5
 
 ref_out, _ = moe.forward(p, cfg, x, capacity_factor=8.0)
-with mesh:
+with jax.set_mesh(mesh):
     ep_out, _ = moe_ep.forward_ep(p, cfg, x, mesh, capacity_factor=8.0)
 err = float(jnp.abs(ep_out - ref_out).max())
 assert err < 1e-4, err
 
 # and the context-based delegation inside moe.forward
-with mesh, common.ep_moe():
+with jax.set_mesh(mesh), common.ep_moe():
     del_out, _ = moe.forward(p, cfg, x, capacity_factor=8.0)
 err2 = float(jnp.abs(del_out - ref_out).max())
 assert err2 < 1e-4, err2
@@ -41,10 +43,11 @@ def test_moe_ep_matches_dense_dispatch():
     out = subprocess.run([sys.executable, "-c", _SCRIPT],
                          capture_output=True, text=True, timeout=560,
                          env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                              "HOME": "/root",
+                              "HOME": os.environ.get("HOME", ""),
                               # the 8-device mesh is a CPU host-platform
                               # trick; never let a libtpu install hijack
                               # the stripped subprocess env
-                              "JAX_PLATFORMS": "cpu"}, cwd="/root/repo")
+                              "JAX_PLATFORMS": "cpu"},
+                         cwd=Path(__file__).resolve().parents[1])
     assert out.returncode == 0, out.stderr[-2000:]
     assert "EP_OK" in out.stdout

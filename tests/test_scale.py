@@ -184,14 +184,13 @@ def test_psum_partials_match_aggregate_masked(seed):
     w = rng.integers(1, 200, size=G).astype(np.float32)
 
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.launch.mesh import make_data_mesh
     stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *locals_)
     mesh = make_data_mesh()               # the in-process 1-device mesh
-    partial = jax.jit(shard_map(
+    partial = jax.jit(jax.shard_map(
         lambda ls, ww, m: psum_masked_partials(ls, m, ww),
-        mesh, in_specs=(P("data"), P("data"), P()),
+        mesh=mesh, in_specs=(P("data"), P("data"), P()),
         out_specs=P()))(stacked, jnp.asarray(w), mask)
     got = mesh_aggregate_masked(glob, [partial])
 

@@ -145,3 +145,71 @@ def test_moe_learns_through_fedepth_with_qsgd_codec():
     on): quantization must not break learning."""
     acc = _learn("qwen3-moe-235b-a22b", codec="qsgd_int8")
     assert acc > 0.5, acc
+
+
+# ------------------------------------------------ eval at full vocabulary
+def _materialized_logits_fn(cfg):
+    """The eval's logits as they were before vocab streaming: the full
+    (B, T, V) array."""
+    from repro.models import common as mcommon
+    lm = build(cfg)
+
+    @jax.jit
+    def logits(p, toks):
+        h, _ = lm.forward_hidden(p, toks, kernel_force="ref")
+        h = mcommon.rms_norm(h, p["final_norm"], cfg.norm_eps)
+        w = p["embed"].T if cfg.tie_embeddings else p["lm_head"]
+        return h @ w
+
+    return logits
+
+
+def _materialized_lm_accuracy(logits, params, x, y, batch):
+    correct = total = 0
+    for i in range(0, len(x), batch):
+        yb = jnp.asarray(y[i:i + batch])
+        pred = jnp.argmax(logits(params, jnp.asarray(x[i:i + batch])), -1)
+        correct += int(((pred == yb) & (yb >= 0)).sum())
+        total += int((yb >= 0).sum())
+    return correct / max(total, 1)
+
+
+@pytest.mark.parametrize("tile_elems", [1 << 26, 24 * 128])
+def test_argmax_over_vocab_matches_argmax(tile_elems, monkeypatch):
+    """Streaming the argmax over vocab tiles (one tile, and several with
+    a ragged last tile) picks exactly jnp.argmax's index, ties included."""
+    from repro.fl.strategies import common
+    monkeypatch.setattr(common, "_ARGMAX_TILE_ELEMS", tile_elems)
+    key = jax.random.PRNGKey(0)
+    x = jax.random.normal(key, (3, 8, 16))
+    w = jax.random.normal(jax.random.fold_in(key, 1), (16, 300))
+    w = w.at[:, 290].set(w[:, 5])        # exact tie across tiles
+    got = common.argmax_over_vocab(x, w)
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(jnp.argmax(x @ w, -1)))
+
+
+@pytest.mark.parametrize("arch", ["mamba2-370m", "yi-6b"])
+def test_lm_accuracy_matches_materialized_logits(arch, monkeypatch):
+    """The streamed eval returns the materialized eval's accuracy.  Half
+    the labels are set to the model's own argmax so the accuracy being
+    compared is far from 0, and a few positions are ignored (-100)."""
+    from repro.fl.strategies import common
+    monkeypatch.setattr(common, "_ARGMAX_TILE_ELEMS", 8 * 12 * 128)
+    common.lm_predict_jit.cache_clear()
+    cfg = get_reduced_config(arch)
+    params = build(cfg).init(jax.random.PRNGKey(3))
+    data = build_seq_data(2, vocab_size=cfg.vocab_size, seq_len=12,
+                          n_test=40, seed=1)
+    logits = _materialized_logits_fn(cfg)
+    pred = np.asarray(jnp.argmax(logits(params, jnp.asarray(data.x_test)),
+                                 -1))
+    y = np.where(np.arange(data.y_test.size).reshape(data.y_test.shape)
+                 % 2 == 0, pred, data.y_test)
+    y[:, :3] = -100
+    want = _materialized_lm_accuracy(logits, params, data.x_test, y, 16)
+    got = common.lm_accuracy(cfg, params, data.x_test, y,
+                             kernel_force="ref", batch=16)
+    common.lm_predict_jit.cache_clear()
+    assert want > 0.4
+    assert got == want

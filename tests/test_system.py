@@ -11,6 +11,7 @@ The core claim chain under test:
 """
 import subprocess
 import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -95,7 +96,7 @@ def _run_cli(mod, args, timeout=560):
     env["PYTHONPATH"] = "src"
     return subprocess.run([sys.executable, "-m", mod, *args],
                           capture_output=True, text=True, timeout=timeout,
-                          env=env, cwd="/root/repo")
+                          env=env, cwd=Path(__file__).resolve().parents[1])
 
 
 @pytest.mark.parametrize("args", [

@@ -148,7 +148,7 @@ def test_train_step_on_host_mesh():
     opt = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
     toks = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0,
                               cfg.vocab_size)
-    with mesh:
+    with jax.set_mesh(mesh):
         jitted = jax.jit(step, in_shardings=(pspecs, None, None),
                          out_shardings=(pspecs, None, None))
         p2, o2, m = jitted(params, opt, {"tokens": toks, "labels": toks})
