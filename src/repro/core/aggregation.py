@@ -18,7 +18,7 @@ from typing import List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
-from repro.obs import active as _obs_active
+from repro.obs import active as _obs_active, annotate as _annotate
 
 # NOTE on buffer donation (core/jit_utils.py): the aggregation jits are
 # deliberately NOT donated.  Client payloads are not private buffers:
@@ -76,7 +76,8 @@ def _finite_filter(client_params: tuple, *aligned: Sequence):
     legacy behavior).  One jitted finiteness reduction per client; the
     all-finite path returns the inputs untouched, so healthy rounds are
     bitwise identical to the unguarded aggregator."""
-    flags = [bool(_all_finite_jit(p)) for p in client_params]
+    with _annotate("aggregate.finite", clients=len(client_params)):
+        flags = [bool(_all_finite_jit(p)) for p in client_params]
     if all(flags):
         return (client_params,) + aligned
     obs = _obs_active()

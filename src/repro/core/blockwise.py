@@ -24,25 +24,24 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import time
 from typing import Any, Callable, Dict, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.decomposition import Decomposition
 from repro.core.jit_utils import donate, donation_supported
 from repro.models import common, resnet as resnet_mod, vit as vit_mod
-from repro.obs import active as obs_active
+from repro.obs import active as obs_active, annotate, span_if
 
 
 def _jit_cache_probe(cache: dict, key, build, *, name: str, audit=None):
     """``cache.setdefault(key, build())`` with telemetry: when a capture
-    is active, count the hit/miss and time the builder (python trace
-    construction; XLA compile itself lands in the first dispatch, which
-    the scheduler's ``group_update_seconds`` covers).  The disabled path
-    is the bare two-line probe every jit cache in the repo already
-    uses.
+    is active, count the hit/miss.  ``build`` only wraps a function in
+    a lazy ``jax.jit``; the compile itself lands in the first dispatch,
+    which a profiler trace shows.  The disabled path is the bare
+    two-line probe every jit cache in the repo already uses.
 
     ``audit`` is the memory-conformance hook
     (:class:`repro.obs.audit.MemoryAuditor`): a callback invoked with
@@ -58,11 +57,8 @@ def _jit_cache_probe(cache: dict, key, build, *, name: str, audit=None):
             audit(cache[key])
         return cache[key]
     if key not in cache:
-        t0 = time.perf_counter()
         cache[key] = build()
         obs.metrics.counter("jit_cache_misses", cache=name).inc()
-        obs.metrics.histogram("jit_build_seconds", cache=name).observe(
-            time.perf_counter() - t0)
     else:
         obs.metrics.counter("jit_cache_hits", cache=name).inc()
     if audit is not None:
@@ -400,10 +396,12 @@ def make_block_step(runner: BlockRunner, lo: int, hi: int, j: int, *,
     for the prefix — but the prefix forward itself is re-billed per step
     (the pre-:class:`PrefixCache` execution contract, kept as the
     reference path behind ``prefix_cache=False``).  The (train, vel)
-    carry is donated so the step updates it in place on gpu/tpu."""
+    carry is donated so the step updates it in place on gpu/tpu.  Its
+    program is ``jit_recompute_step``; ``jit_step`` is the buffered
+    step alone."""
 
     @functools.partial(jax.jit, donate_argnums=donate(1, 2))
-    def step(params, train, vel, anchor, batch):
+    def recompute_step(params, train, vel, anchor, batch):
         def loss(tp):
             z_in = runner.embed(params, batch)
             if lo > 0:
@@ -418,7 +416,7 @@ def make_block_step(runner: BlockRunner, lo: int, hi: int, j: int, *,
         train = jax.tree.map(lambda t, v: t - lr * v, train, vel)
         return train, vel
 
-    return step
+    return recompute_step
 
 
 def make_buffered_block_step(runner: BlockRunner, lo: int, hi: int, j: int,
@@ -474,6 +472,13 @@ def make_prefix_advance(runner: BlockRunner, lo: int, hi: int):
     return adv
 
 
+def host_nbytes(tree) -> int:
+    """Bytes of the host (numpy) arrays in ``tree``: what a device
+    program called with it copies to the device."""
+    return sum(int(leaf.nbytes) for leaf in jax.tree.leaves(tree)
+               if isinstance(leaf, np.ndarray))
+
+
 class PrefixCache:
     """Buffered z_{lo-1} activations for one client's depth-wise update —
     the paper's prefix-once execution contract, made explicit.
@@ -521,21 +526,23 @@ class PrefixCache:
         obs = obs_active()
         if (self.zs is None or not self.runner.prefix_stable
                 or lo < self._lo):
-            fresh = self.zs is None
-            fwd = self._jit(("prefix", lo),
-                            lambda: make_prefix_forward(self.runner, lo))
-            self.zs = [fwd(params, b) for b in batches]
+            # first buffering of an update vs a forced re-buffer
+            # (unstable prefix / backward transition)
+            mode = "buffer" if self.zs is None else "rebuffer"
+            with annotate("prefix", mode=mode,
+                          host_bytes=host_nbytes(batches)):
+                fwd = self._jit(("prefix", lo),
+                                lambda: make_prefix_forward(self.runner,
+                                                            lo))
+                self.zs = [fwd(params, b) for b in batches]
             if obs is not None:
-                # first buffering of an update vs a forced re-buffer
-                # (unstable prefix / backward transition)
-                obs.metrics.counter(
-                    "prefix_cache_buffer" if fresh
-                    else "prefix_cache_rebuffer").inc()
+                obs.metrics.counter("prefix_cache_" + mode).inc()
         elif lo != self._lo:
-            adv = self._jit(("advance", self._lo, lo),
-                            lambda: make_prefix_advance(self.runner,
-                                                        self._lo, lo))
-            self.zs = [adv(params, z) for z in self.zs]
+            with annotate("prefix", mode="advance", host_bytes=0):
+                adv = self._jit(("advance", self._lo, lo),
+                                lambda: make_prefix_advance(self.runner,
+                                                            self._lo, lo))
+                self.zs = [adv(params, z) for z in self.zs]
             if obs is not None:
                 obs.metrics.counter("prefix_cache_advance").inc()
         self._lo = lo
@@ -584,51 +591,57 @@ def client_update(runner: BlockRunner, params, dec: Decomposition, batches,
         cache = PrefixCache(runner, jit_cache=step_cache)
 
     obs = obs_active()
+    # what every step's call copies to the device: the host batches
+    step_bytes = local_steps * host_nbytes(batches)
     for j, (lo, hi) in enumerate(dec.blocks):
-        block_span = None if obs is None else \
-            obs.tracer.begin("block", lo=lo, hi=hi, j=j)
-        zs = cache.prepare(params, batches, lo) if cache is not None \
-            else None
-        train = runner.split(params, lo, hi)
-        # the FedProx anchor aliases the split views (cheap, never
-        # donated); the (train, vel) carry gets private buffers when the
-        # backend honors donation, so the step can update it in place
-        # without invalidating ``params``' leaves
-        anchor = jax.tree.map(jnp.asarray, train)
-        if donation_supported():
-            train = jax.tree.map(jnp.copy, train)
-        vel = jax.tree.map(jnp.zeros_like, train)
+        with span_if(obs, "block", lo=lo, hi=hi, j=j):
+            zs = cache.prepare(params, batches, lo) if cache is not None \
+                else None
+            with annotate("block.setup"):
+                train = runner.split(params, lo, hi)
+                # the FedProx anchor aliases the split views (cheap,
+                # never donated); the (train, vel) carry gets private
+                # buffers when the backend honors donation, so the step
+                # can update it in place without invalidating
+                # ``params``' leaves
+                anchor = jax.tree.map(jnp.asarray, train)
+                if donation_supported():
+                    train = jax.tree.map(jnp.copy, train)
+                vel = jax.tree.map(jnp.zeros_like, train)
 
-        key = ("buffered" if cache is not None else "recompute",
-               lo, hi, j, lr, momentum, prox_mu)
-        make = make_buffered_block_step if cache is not None \
-            else make_block_step
-        audit = None
-        if obs is not None and obs.audit is not None:
-            step_args = (params, train, vel, anchor) \
-                + ((zs[0],) if cache is not None else ()) + (batches[0],)
-            audit = (lambda fn, a=step_args, lo=lo, hi=hi:
-                     obs.audit.audit_block_step(
-                         fn, a, family=runner.family, lo=lo, hi=hi,
-                         variant="buffered" if cache is not None
-                         else "recompute", n_batches=len(batches)))
-        step = _jit_cache_probe(
-            step_cache, key,
-            lambda: make(runner, lo, hi, j, lr=lr, momentum=momentum,
-                         prox_mu=prox_mu),
-            name="block_step", audit=audit)
+                key = ("buffered" if cache is not None else "recompute",
+                       lo, hi, j, lr, momentum, prox_mu)
+                make = make_buffered_block_step if cache is not None \
+                    else make_block_step
+                audit = None
+                if obs is not None and obs.audit is not None:
+                    step_args = (params, train, vel, anchor) \
+                        + ((zs[0],) if cache is not None else ()) \
+                        + (batches[0],)
+                    audit = (lambda fn, a=step_args, lo=lo, hi=hi:
+                             obs.audit.audit_block_step(
+                                 fn, a, family=runner.family, lo=lo, hi=hi,
+                                 variant="buffered" if cache is not None
+                                 else "recompute", n_batches=len(batches)))
+                step = _jit_cache_probe(
+                    step_cache, key,
+                    lambda: make(runner, lo, hi, j, lr=lr,
+                                 momentum=momentum, prox_mu=prox_mu),
+                    name="block_step", audit=audit)
 
-        for _ in range(local_steps):
-            if cache is not None:
-                for z_in, batch in zip(zs, batches):
-                    train, vel = step(params, train, vel, anchor, z_in,
-                                      batch)
-            else:
-                for batch in batches:
-                    train, vel = step(params, train, vel, anchor, batch)
-        params = runner.merge(params, train, lo=lo, hi=hi)
-        if block_span is not None:
-            obs.tracer.end(block_span)
+            with annotate("block.steps", steps=local_steps * len(batches),
+                          host_bytes=step_bytes):
+                for _ in range(local_steps):
+                    if cache is not None:
+                        for z_in, batch in zip(zs, batches):
+                            train, vel = step(params, train, vel, anchor,
+                                              z_in, batch)
+                    else:
+                        for batch in batches:
+                            train, vel = step(params, train, vel, anchor,
+                                              batch)
+            with annotate("block.merge"):
+                params = runner.merge(params, train, lo=lo, hi=hi)
 
     return params
 

@@ -39,7 +39,7 @@ from repro.fl.sampling import (CohortSampler, ClientScheduler,
                                VectorizedScheduler, make_scheduler)
 from repro.fl.strategy import (ClientResult, Context, FLStrategy,
                                wire_bytes)
-from repro.obs import make_obs, scope, span_if
+from repro.obs import annotate, make_obs, scope, span_if
 
 SCENARIOS: Dict[str, Tuple[float, ...]] = {
     "fair": (1 / 6, 1 / 3, 1 / 2, 1.0),
@@ -149,9 +149,10 @@ def default_batch_fn(ctx: Context) -> Callable[[int], list]:
     data, sim = ctx.data, ctx.sim
 
     def batch_fn(k: int) -> list:
-        return [data.client_batch(k, sim.batch_size, ctx.rng)
-                for _ in range(max(1, len(data.client_indices[k])
-                                   // sim.batch_size))]
+        with annotate("batch", client=k):
+            return [data.client_batch(k, sim.batch_size, ctx.rng)
+                    for _ in range(max(1, len(data.client_indices[k])
+                                       // sim.batch_size))]
     return batch_fn
 
 
@@ -338,18 +339,18 @@ class RoundEngine:
         sample -> local updates -> uplink encode -> decode ->
         aggregate.  Returns (new_state, up_bytes, down_bytes).
 
-        With ``obs`` enabled this is the telemetry activation boundary
-        for direct callers (benchmarks drive ``run_round`` without
-        ``run``): the round runs inside a ``round`` span with the
-        capture active, and the engine's byte counters accumulate."""
+        The round runs inside a ``round`` span (the profiler's
+        ``repro.round``).  With ``obs`` enabled this is also the
+        telemetry activation boundary for direct callers (benchmarks
+        drive ``run_round`` without ``run``): the capture is active and
+        the engine's byte counters accumulate."""
         inner = self._run_round if self._faultrt is None \
             else self._run_round_resilient
-        if self.obs is None:
-            return inner(state, round_idx, batch_fn)
-        with scope(self.obs), \
-                self.obs.tracer.span("round", round=round_idx,
-                                     engine="round"):
+        with scope(self.obs), span_if(self.obs, "round", round=round_idx,
+                                      engine="round"):
             state, comm, down = inner(state, round_idx, batch_fn)
+        if self.obs is None:
+            return state, comm, down
         m = self.obs.metrics
         m.counter("engine_rounds", engine="round").inc()
         m.counter("engine_up_bytes", engine="round").inc(comm)
@@ -359,9 +360,11 @@ class RoundEngine:
     def _run_round(self, state, round_idx: int,
                    batch_fn: Callable[[int], list]):
         ctx, chan = self.ctx, self.channel
-        cohort = self.sampler.sample(ctx, round_idx)
-        down = sum(chan.downlink_bytes(self.strategy, ctx, state, int(k))
-                   for k in cohort)
+        with annotate("sample"):
+            cohort = self.sampler.sample(ctx, round_idx)
+        with annotate("comm"):
+            down = sum(chan.downlink_bytes(self.strategy, ctx, state,
+                                           int(k)) for k in cohort)
         # fused on-mesh execution+aggregation (ShardedScheduler with
         # aggregate="mesh"): only under the strict no-op codec — a lossy
         # channel needs per-client payloads on the host for
@@ -377,12 +380,15 @@ class RoundEngine:
                 return new_state, comm, down
         results = self.scheduler.run(ctx, self.strategy, state,
                                      cohort, batch_fn)
-        results = [chan.encode_result(self.strategy, ctx, state, int(k), r)
-                   for k, r in zip(cohort, results)]
-        comm = sum(r.comm_bytes if r.comm_bytes is not None
-                   else wire_bytes(r.payload) for r in results)
-        results = [chan.decode_result(r) for r in results]
-        new_state = self.strategy.aggregate(ctx, state, results)
+        with annotate("comm"):
+            results = [chan.encode_result(self.strategy, ctx, state,
+                                          int(k), r)
+                       for k, r in zip(cohort, results)]
+            comm = sum(r.comm_bytes if r.comm_bytes is not None
+                       else wire_bytes(r.payload) for r in results)
+            results = [chan.decode_result(r) for r in results]
+        with span_if(self.obs, "aggregate", clients=len(results)):
+            new_state = self.strategy.aggregate(ctx, state, results)
         if self.obs is not None and self.obs.dynamics is not None:
             self.obs.dynamics.record_round(
                 round_idx, state, results, new_state,

@@ -17,7 +17,7 @@ from typing import Callable, List, Optional, Protocol, Sequence
 import numpy as np
 
 from repro.fl.strategy import ClientResult, Context, FLStrategy
-from repro.obs import active as obs_active
+from repro.obs import active as obs_active, span_if
 
 
 class CohortSampler(Protocol):
@@ -97,13 +97,9 @@ class SequentialScheduler:
 
     def run(self, ctx, strategy, state, cohort, batch_fn):
         obs = obs_active()
-        if obs is None:
-            return [strategy.client_update(ctx, state, int(k),
-                                           batch_fn(int(k)))
-                    for k in cohort]
         results = []
         for k in cohort:
-            with obs.tracer.span("client-update", client=int(k)):
+            with span_if(obs, "client-update", client=int(k)):
                 results.append(strategy.client_update(ctx, state, int(k),
                                                       batch_fn(int(k))))
         return results
@@ -155,35 +151,24 @@ class VectorizedScheduler:
             if (key is None or len(positions) < self.min_group
                     or not stackable(group_batches)):
                 for p in positions:
-                    if obs is not None:
-                        with obs.tracer.span("client-update",
-                                             client=ids[p], fallback=True):
-                            results[p] = strategy.client_update(
-                                ctx, state, ids[p], batches[p])
-                        continue
-                    results[p] = strategy.client_update(
-                        ctx, state, ids[p], batches[p])
+                    with span_if(obs, "client-update", client=ids[p],
+                                 fallback=True):
+                        results[p] = strategy.client_update(
+                            ctx, state, ids[p], batches[p])
                 if obs is not None:
                     obs.metrics.counter("scheduler_fallback_clients",
                                         scheduler="vectorized",
                                         ).inc(len(positions))
                 continue
-            if obs is None:
+            # one span per stacked vmap dispatch: the host's enqueue,
+            # not the group's device time, which the profiler's trace
+            # holds under the group update's program
+            with span_if(obs, "cohort-group", size=len(positions),
+                         signature=str(key)):
                 outs = update_batched(ctx, state,
                                       [ids[p] for p in positions],
                                       group_batches)
-            else:
-                # one span per stacked vmap dispatch; the observed
-                # seconds include XLA compile on the group's first call
-                # (jit_cache_* metrics tell the two apart)
-                with obs.tracer.span("cohort-group", size=len(positions),
-                                     signature=str(key)) as sp:
-                    outs = update_batched(ctx, state,
-                                          [ids[p] for p in positions],
-                                          group_batches)
-                obs.metrics.histogram("group_update_seconds",
-                                      signature=str(key),
-                                      ).observe(sp.wall_seconds)
+            if obs is not None:
                 obs.metrics.counter("group_dispatches",
                                     scheduler="vectorized").inc()
                 obs.metrics.counter("group_clients",

@@ -32,6 +32,7 @@ from repro.fl.registry import register
 from repro.fl.strategy import ClientResult, wire_bytes
 from repro.fl.strategies import common
 from repro.models import resnet
+from repro.obs import annotate
 
 
 @register("fedepth")
@@ -96,14 +97,15 @@ class FedepthStrategy:
                 local_steps=ctx.sim.local_steps, prox_mu=self.prox_mu,
                 step_cache=ctx.caches.setdefault("fedepth_step", {}),
                 prefix_cache=ctx.prefix_cache)
-        result = ClientResult(local, float(ctx.sizes[client_id]))
-        if self.masked_aggregation:
-            mask = aggregation.trained_mask_for(
-                state, ctx.decomps[client_id], self.runner)
-            # only the trained model crosses the wire; the mask is
-            # derivable server-side from the client's decomposition
-            result.payload = (local, mask)
-            result.comm_bytes = wire_bytes(local)
+        with annotate("payload"):
+            result = ClientResult(local, float(ctx.sizes[client_id]))
+            if self.masked_aggregation:
+                mask = aggregation.trained_mask_for(
+                    state, ctx.decomps[client_id], self.runner)
+                # only the trained model crosses the wire; the mask is
+                # derivable server-side from the client's decomposition
+                result.payload = (local, mask)
+                result.comm_bytes = wire_bytes(local)
         return result
 
     # ---------------------------------------------- batched capability
@@ -313,11 +315,13 @@ def _mkd_step(cfg, M: int, lr: float, momentum: float):
     def loss(plist, batch):
         return mkd.mkd_loss(logits_fn, plist, batch, task_fn)
 
+    # its own program name, ``jit_mkd_step``: ``jit_step`` is the
+    # buffered block step alone
     @jax.jit
-    def step(plist, vels, batch):
+    def mkd_step(plist, vels, batch):
         grads = jax.grad(loss)(plist, batch)
         vels = jax.tree.map(lambda v, g: momentum * v + g, vels, grads)
         plist = jax.tree.map(lambda p, v: p - lr * v, plist, vels)
         return plist, vels
 
-    return step
+    return mkd_step

@@ -117,6 +117,7 @@ def chunked_cross_entropy(
                                block_v=block_v, vocab_size=V)
     nll = pl.pallas_call(
         kernel,
+        name="_ce_kernel",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_t, D), lambda t, v: (t, 0)),

@@ -121,6 +121,7 @@ def mamba2_scan(
 
     y, hT = pl.pallas_call(
         kernel,
+        name="_ssd_kernel",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, block_t, P), lambda b, h, t: (b, h, t, 0)),

@@ -17,12 +17,15 @@ substrate (docs/observability.md):
   ``JsonlHistorySink``), Chrome trace-event format (Perfetto), and a
   Prometheus textfile snapshot.
 
-**Zero overhead when disabled.**  Both engines take ``obs=`` (default
-``None`` = off).  Off means: no tracer, no registry, and every
+**Off by default, profiler spans always.**  Both engines take ``obs=``
+(default ``None`` = off).  Off means: no tracer, no registry, and every
 instrumented call site guarded by one ``active()`` lookup returning
 ``None`` — histories, aggregated params, and the legacy trace are
-bitwise-identical to the pre-telemetry code path (tests/test_obs.py;
-overhead benched in ``benchmarks/obs_overhead.py``).
+bitwise-identical to the pre-telemetry code path (tests/test_obs.py).
+The program's spans reach the profiler either way
+(:func:`~repro.obs.trace.annotate`, ``repro.<kind>``): with no profiler
+running each costs about a microsecond on the host and records nothing
+(docs/observability.md §Profiler).
 
 Enablement flows through one contextvar: an engine whose ``obs`` is set
 wraps its run in :func:`activate`, and deep sites that never see the
@@ -42,7 +45,7 @@ from repro.obs.dynamics import DynamicsAnalyzer  # noqa: F401
 from repro.obs.metrics import (Counter, Gauge, Histogram,  # noqa: F401
                                MetricsRegistry)
 from repro.obs.trace import (LEGACY_FIELDS, SYS_EVENT_KINDS,  # noqa: F401
-                             Event, Span, SysEvent, Tracer)
+                             Event, Span, SysEvent, Tracer, annotate)
 
 
 @dataclasses.dataclass
@@ -145,15 +148,16 @@ def scope(obs: Optional[Obs]):
 
 
 def span_if(obs: Optional[Obs], kind: str, **attrs):
-    """``obs.tracer.span(kind, **attrs)`` when enabled, a no-op context
-    otherwise — the one-line guard instrumented call sites use."""
+    """``obs.tracer.span(kind, **attrs)`` when enabled, the profiler
+    annotation ``repro.<kind>`` alone otherwise (yielding ``None``) —
+    the one path instrumented call sites take."""
     if obs is None:
-        return contextlib.nullcontext()
+        return annotate(kind, **attrs)
     return obs.tracer.span(kind, **attrs)
 
 
 __all__ = [
-    "Obs", "make_obs", "active", "activate", "scope", "span_if",
+    "Obs", "make_obs", "active", "activate", "scope", "span_if", "annotate",
     "Tracer", "Span", "Event", "SysEvent", "LEGACY_FIELDS",
     "SYS_EVENT_KINDS",
     "MetricsRegistry", "Counter", "Gauge", "Histogram",

@@ -20,6 +20,13 @@ walk them without reverse-engineering tuple positions:
   exact tuple, so the legacy list stays byte-identical per seed when
   telemetry is on (regression-tested in tests/test_obs.py).
 
+Every span also opens a ``jax.profiler.TraceAnnotation`` named
+``repro.<kind>`` (:func:`annotate`), so an operator's
+``jax.profiler.trace(dir)`` around ``engine.run()`` sees the program's
+spans on the profiler's own clock, beside the device's programs — with
+the tracer on or off.  No profiler running, an annotation costs about a
+microsecond and records nothing.
+
 The tracer never touches the simulation's rng streams or any jax value —
 enabling it cannot perturb an experiment (asserted bitwise in
 tests/test_obs.py).
@@ -30,6 +37,11 @@ import contextlib
 import dataclasses
 import time
 from typing import Any, Callable, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
+
+#: Prefix of every profiler span the program writes.
+PROFILER_PREFIX = "repro."
 
 #: The documented field order of the legacy ``AsyncEngine.trace`` tuples
 #: — and, by construction, of :class:`SysEvent`'s leading fields.  The
@@ -49,6 +61,22 @@ LEGACY_FIELDS = ("kind", "t", "client", "version", "extra")
 #: ``extra`` is the round/version saved).
 SYS_EVENT_KINDS = ("dispatch", "dispatch_forced", "finish", "miss",
                    "aggregate", "fail", "quarantine", "checkpoint")
+
+
+class _Annotation(TraceAnnotation):
+    """A profiler annotation whose ``with`` target is ``None``, which is
+    what ``repro.obs.span_if`` yields without a capture."""
+
+    def __enter__(self):
+        super().__enter__()
+
+
+def annotate(kind: str, **attrs) -> TraceAnnotation:
+    """The profiler span ``repro.<kind>``, with ``attrs`` as its stats
+    (``k=v``).  It records only while a profiler trace is running
+    (``jax.profiler.trace``/``start_trace``); otherwise it costs one
+    ``TraceMe`` check."""
+    return _Annotation(PROFILER_PREFIX + kind, **attrs)
 
 
 @dataclasses.dataclass
@@ -138,7 +166,8 @@ class Tracer:
     wall-clock engine leaves it unset (sim stamps 0.0).  Spans nest via
     an explicit stack, so ``span_id``/``parent_id`` reconstruct the
     round → cohort-group → client-update → block hierarchy without any
-    global state."""
+    global state.  Each span also holds a profiler annotation
+    (:func:`annotate`) open from ``begin`` to ``end``."""
 
     def __init__(self, sim_clock: Optional[Callable[[], float]] = None):
         self.sim_clock = sim_clock
@@ -146,6 +175,7 @@ class Tracer:
         self.events: List[Event] = []
         self.sys_events: List[SysEvent] = []
         self._stack: List[int] = []
+        self._annotations: Dict[int, TraceAnnotation] = {}
         self._next_id = 0
 
     def reset(self) -> None:
@@ -156,6 +186,7 @@ class Tracer:
         self.events.clear()
         self.sys_events.clear()
         self._stack.clear()
+        self._annotations.clear()
         self._next_id = 0
 
     # ------------------------------------------------------------- clocks
@@ -172,10 +203,15 @@ class Tracer:
         self._next_id += 1
         self.spans.append(span)
         self._stack.append(span.span_id)
+        ann = self._annotations[span.span_id] = annotate(kind, **attrs)
+        ann.__enter__()
         return span
 
     def end(self, span: Span, **attrs) -> Span:
         """Close a span (stamps both end clocks; merges extra attrs)."""
+        ann = self._annotations.pop(span.span_id, None)
+        if ann is not None:
+            ann.__exit__(None, None, None)
         span.wall_end = time.perf_counter()
         span.sim_end = self._sim_now()
         if attrs:

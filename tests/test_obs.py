@@ -1,4 +1,4 @@
-"""Telemetry layer (docs/observability.md): zero-overhead-when-disabled
+"""Telemetry layer (docs/observability.md): off-is-bitwise
 contract, typed-event projection of the legacy trace, stable schema,
 metrics registry semantics, exporters, the trace report, and the
 JsonlHistorySink non-finite-JSON fix."""
@@ -222,7 +222,7 @@ def test_deep_sites_record_metrics():
                       scheduler="vectorized", codec="qsgd_int8", obs="on")
     eng.run(eval_every=2)
     names = {m["name"] for m in eng.obs.metrics.snapshot()}
-    assert {"jit_cache_misses", "group_dispatches", "group_update_seconds",
+    assert {"jit_cache_misses", "group_dispatches", "group_clients",
             "codec_encode_ratio", "codec_encoded_bytes",
             "ef_residual_norm", "engine_up_bytes"} <= names
     kinds = {s.kind for s in eng.obs.tracer.spans}
