@@ -2,16 +2,20 @@
 """Readings that set a cell's limits, taken in one process on the chip.
 
     python3 bench/calibrate.py --config <config> --traffic <mix> \
-        --seeds 12 --control-seeds 3 --fault-seeds 3 [--first-seed N]
+        --seeds 16 --control-seeds 4 --fault-seeds 4 [--first-seed N]
 
 For each seed it builds the program as a run does, drives the compared
 rounds, and reads the numbers of ``compare.py`` against the float32
-reference at ``highest`` precision (the lower readings).  On the first
-``--control-seeds`` seeds it also reads the control, the reference in
-bfloat16 put in the program's place, and on the first ``--fault-seeds``
-the fault of a half batch: the reference in the program's place with
-half of every batch left out (the upper readings).  One JSON line per
-reading on standard output.  The benchmark's own runs never run this.
+reference at ``highest`` precision, each round of the reference run from
+the program's params at that round's start (the lower readings).  On the
+first ``--control-seeds`` seeds it also reads the control, the reference
+in bfloat16 put in the program's place, and on the first
+``--fault-seeds`` the fault of a half batch: the reference in the
+program's place with half of every batch left out (the upper readings);
+each of them too runs every round from the program's params at its
+start.  One JSON line per reading on standard output, with the worst
+round and each round's numbers.  The benchmark's own runs never run
+this.
 """
 import time
 
@@ -50,7 +54,7 @@ def readings(cell, seeds, control_seeds, fault_seeds, *, require_tpu=True,
     fault_half_batch) with the numbers of ``compare.py``."""
     import jax.numpy as jnp
     import harness
-    from compare import numbers
+    from compare import by_round, worst
 
     harness.check_device(cell.chips, require_tpu)
     caches = {}
@@ -61,38 +65,38 @@ def readings(cell, seeds, control_seeds, fault_seeds, *, require_tpu=True,
                               caches=caches)
         if i == 0:
             bench.warm_up()
-        init, p_first, p_last, rounds = bench.compared_rounds(n)
+        params, rounds = bench.compared_rounds(n)
         bench.close()
-        r_first, r_last = harness.reference_params(cell, init, rounds)
+        starts = params[:-1]
+        refs = harness.reference_params(cell, starts, rounds)
         t1 = time.perf_counter()
 
-        def emit_numbers(kind, first, last, **extra):
-            got = numbers(cell.model.leaves, init, (first, r_first),
-                          (last, r_last))
+        def emit_numbers(kind, progs, **extra):
+            per = by_round(cell.model.leaves, starts, progs, refs)
+            got = worst(per)
             emit({"cell": cell.name, "kind": kind, "seed": seed,
                   **{k: v for k, (v, _) in got.items()},
-                  "leaves": {k: leaf for k, (_, leaf) in got.items()},
+                  "worst": {k: where for k, (_, where) in got.items()},
+                  "by_round": {k: [rd[k][0] for rd in per] for k in got},
                   **extra})
 
-        emit_numbers("program", p_first, p_last,
-                     program_and_ref_s=t1 - t0)
+        emit_numbers("program", params[1:], program_and_ref_s=t1 - t0)
         if i < control_seeds:
-            c_first, c_last = harness.reference_params(
-                cell, init, rounds, dtype=jnp.bfloat16, precision="default")
-            emit_numbers("control_bf16", c_first, c_last)
+            emit_numbers("control_bf16", harness.reference_params(
+                cell, starts, rounds, dtype=jnp.bfloat16,
+                precision="default"))
         if i < fault_seeds:
-            f_first, f_last = harness.reference_params(
-                cell, init, rounds, rows=half_rows)
-            emit_numbers("fault_half_batch", f_first, f_last)
+            emit_numbers("fault_half_batch", harness.reference_params(
+                cell, starts, rounds, rows=half_rows))
 
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--config", required=True)
     ap.add_argument("--traffic", required=True)
-    ap.add_argument("--seeds", type=int, default=12)
-    ap.add_argument("--control-seeds", type=int, default=3)
-    ap.add_argument("--fault-seeds", type=int, default=3)
+    ap.add_argument("--seeds", type=int, default=16)
+    ap.add_argument("--control-seeds", type=int, default=4)
+    ap.add_argument("--fault-seeds", type=int, default=4)
     ap.add_argument("--first-seed", type=int, default=4_100_000_000)
     args = ap.parse_args(argv)
     sys.path.insert(0, str(ROOT / "src"))

@@ -18,9 +18,10 @@ Order of a run:
 2. the window: whole rounds of the same engine until ``--seconds`` have
    passed, each ended by ``block_until_ready``;
 3. after the window, with the program's state freed: the reference
-   follows the compared rounds on the inputs it draws from the seed's
-   stream, each client on its tier's blocks from the mix's table, and
-   the numbers of ``compare.py`` are held to the cell's limits.
+   runs each compared round from the program's params at that round's
+   start, on the inputs it draws from the seed's stream, each client on
+   its tier's blocks from the mix's table, and the numbers of
+   ``compare.py`` are held to the cell's limits.
 """
 from __future__ import annotations
 
@@ -278,21 +279,20 @@ class Bench:
         self.round += 1
 
     def compared_rounds(self, n: int):
-        """The first ``n`` rounds.  Returns the initial params, the params
-        after the first and the last round (on the host) and, per round,
-        (blocks, batches, weight) of each client in cohort order: the
-        inputs the seed gives those rounds, drawn again by the reference
-        from a copy of the random stream as the paper's protocol draws
-        them, with each client's blocks from its tier's row of the
-        traffic mix, whatever the program did with its own draws."""
+        """The first ``n`` rounds.  Returns the params at every round
+        boundary, ``[init, after round 1, ..., after round n]`` (on the
+        host) and, per round, (blocks, batches, weight) of each client in
+        cohort order: the inputs the seed gives those rounds, drawn again
+        by the reference from a copy of the random stream as the paper's
+        protocol draws them, with each client's blocks from its tier's
+        row of the traffic mix, whatever the program did with its own
+        draws."""
         from fedepth_ref import draw_rounds
         stream = copy.deepcopy(self.ctx.rng)
-        init = host(self.state)
-        after = []
-        for r in range(n):
+        params = [host(self.state)]
+        for _ in range(n):
             self.run_round()
-            if r == 0 or r == n - 1:
-                after.append(host(self.state))
+            params.append(host(self.state))
         data, t = self.ctx.data, self.cell.traffic
         drawn = draw_rounds(stream, data.client_indices, n,
                             cohort_size(t), t["batch_size"],
@@ -301,7 +301,7 @@ class Bench:
         rounds = [[(self.blocks[self.tiers[k]], batches,
                     float(len(data.client_indices[k])))
                    for k, batches in clients] for clients in drawn]
-        return init, after[0], after[-1], rounds
+        return params, rounds
 
     def finite(self) -> bool:
         import jax
@@ -315,32 +315,30 @@ class Bench:
         gc.collect()
 
 
-def reference_params(cell: Cell, init, rounds, **variant):
-    """The reference after the first and the last of ``rounds`` (host)."""
+def reference_params(cell: Cell, starts, rounds, **variant):
+    """The reference's params after each of ``rounds`` (host), round
+    ``r`` run from ``starts[r]``: the program's params at its start."""
     from fedepth_ref import Reference
 
     t = cell.traffic
     ref = Reference(cell.model, cell.sizes, lr=t["lr"],
                     momentum=t["momentum"], local_steps=t["local_steps"],
                     **variant)
-    params = init
-    after = []
-    for r, clients in enumerate(rounds):
-        params = ref.round(params, clients)
-        if r == 0 or r == len(rounds) - 1:
-            after.append(host(params))
-    return after[0], after[-1]
+    return [host(ref.round(start, clients))
+            for start, clients in zip(starts, rounds)]
 
 
-def judge(cell: Cell, init, prog_first, prog_last, ref_first, ref_last):
-    """name -> (value, limit, worst leaf) and whether every value is
+def judge(cell: Cell, params, refs):
+    """``params``: the program's at every round boundary; ``refs``: the
+    reference's after each round.  Returns name -> (value, limit, worst
+    round and leaf), each round's numbers, and whether every value is
     within its limit."""
-    from compare import numbers
-    got = numbers(cell.model.leaves, init, (prog_first, ref_first),
-                  (prog_last, ref_last))
-    checks = {k: (v, cell.limits[k], leaf) for k, (v, leaf) in got.items()}
+    from compare import by_round, worst
+    rounds = by_round(cell.model.leaves, params[:-1], params[1:], refs)
+    checks = {k: (v, cell.limits[k], leaf)
+              for k, (v, leaf) in worst(rounds).items()}
     ok = all(math.isfinite(v) and v <= lim for v, lim, _ in checks.values())
-    return checks, ok
+    return checks, rounds, ok
 
 
 def per_layer(cell: Cell, red, rounds: int, cohorts,
@@ -370,8 +368,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
     bench = Bench(cell, seed, trace=trace, kernel_force=kernel_force)
     bench.warm_up()
     mismatch = bench.decomposition_mismatch()
-    init, prog_first, prog_last, recorded = bench.compared_rounds(
-        cell.traffic["compare_rounds"])
+    params, recorded = bench.compared_rounds(cell.traffic["compare_rounds"])
     bench.cohorts = []
 
     logdir = tempfile.mkdtemp(prefix="bench-trace-") if trace else None
@@ -399,13 +396,12 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
     del bench
 
     t_ref = time.perf_counter()
-    ref_first, ref_last = reference_params(cell, init, recorded)
+    refs = reference_params(cell, params[:-1], recorded)
     print(f"phases: setup_s {setup_s:.3f} (compile {setup_compile_s:.3f}) "
           f"window_s {window_s:.3f} rounds {rounds} compiles_in_window "
           f"{compiles} reference_s {time.perf_counter() - t_ref:.3f}",
           file=sys.stderr)
-    checks, ok = judge(cell, init, prog_first, prog_last, ref_first,
-                       ref_last)
+    checks, per_round, ok = judge(cell, params, refs)
 
     dev = {"platform": device.platform, "kind": device.device_kind,
            "count": jax.device_count(), "memory_peak_bytes": memory_peak}
@@ -427,7 +423,9 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
                                for k, (v, u) in values.items() if k in names},
                       device=dev)
     for k, (v, lim, leaf) in checks.items():
-        print(f"worst leaf of {k}: {leaf}", file=sys.stderr)
+        print(f"{k} by round: "
+              + " ".join(f"{rd[k][0]:.6g}" for rd in per_round)
+              + f"; worst: {leaf}", file=sys.stderr)
     result["checks"] = {k: {"value": v, "limit": lim}
                         for k, (v, lim, _) in checks.items()}
     result["checks"]["compiles_in_window"] = {"value": compiles, "limit": 0}

@@ -15,13 +15,15 @@ SEED = 3_900_000_017
 
 
 def _numbers(cell, **variant):
+    """The numbers of the reference's ``variant`` put in the program's
+    place, each round run from the program's params at its start."""
     bench = harness.Bench(cell, SEED, kernel_force=kernel_force(cell))
-    init, _, _, rounds = bench.compared_rounds(cell.traffic["compare_rounds"])
+    params, rounds = bench.compared_rounds(cell.traffic["compare_rounds"])
     bench.close()
-    r_first, r_last = harness.reference_params(cell, init, rounds)
-    c_first, c_last = harness.reference_params(cell, init, rounds, **variant)
-    return compare.numbers(cell.model.leaves, init, (c_first, r_first),
-                           (c_last, r_last))
+    starts = params[:-1]
+    refs = harness.reference_params(cell, starts, rounds)
+    controls = harness.reference_params(cell, starts, rounds, **variant)
+    return compare.numbers(cell.model.leaves, starts, controls, refs)
 
 
 def test_control_fails_the_limits(small_cell):
@@ -46,7 +48,31 @@ def test_round_that_returns_its_state_unchanged(small_cell, monkeypatch):
                         lambda self, state, rd, batch_fn: (state, 0, 0))
     r = _broken_run(small_cell)
     assert r["correct"] is False
-    assert r["checks"]["gap_first"]["value"] == pytest.approx(1.0)
+    assert r["checks"]["gap"]["value"] == pytest.approx(1.0)
+
+
+def test_fault_in_a_later_round_fails(small_cell, monkeypatch, capsys):
+    """From the second round on the round returns its state unchanged.
+    The reference restarts each round from the program's params, and
+    still the later round reads 1."""
+    from repro.fl.engine import RoundEngine
+    run_round = RoundEngine.run_round
+
+    def stale(self, state, rd, batch_fn):
+        if rd >= 1:
+            return state, 0, 0
+        return run_round(self, state, rd, batch_fn)
+
+    monkeypatch.setattr(RoundEngine, "run_round", stale)
+    r = _broken_run(small_cell)
+    assert r["correct"] is False
+    assert r["checks"]["gap"]["value"] == pytest.approx(1.0)
+    by_round = next(line for line in capsys.readouterr().err.splitlines()
+                    if line.startswith("gap by round:"))
+    first, second = by_round.split(":", 1)[1].split(";")[0].split()[:2]
+    assert float(first) < small_cell.limits["gap"]
+    assert float(second) == pytest.approx(1.0)
+    assert "worst: round 2," in by_round
 
 
 def test_half_of_every_batch_left_out(small_cell, monkeypatch):
@@ -82,3 +108,23 @@ def test_decomposition_other_than_the_mix_states(small_cell, monkeypatch):
     r = _broken_run(small_cell)
     assert r["correct"] is False
     assert r["checks"]["decomposition_mismatch"]["value"] == 1
+
+
+def test_calibration_reads_every_round_of_each_kind(small_cell):
+    """``calibrate.readings`` gives the program's numbers under the
+    limits and the control's and the half batch's over one of them, each
+    with its worst round and every round's value."""
+    lines = []
+    calibrate.readings(small_cell, [SEED], 1, 1, require_tpu=False,
+                       kernel_force=kernel_force(small_cell),
+                       emit=lines.append)
+    assert [r["kind"] for r in lines] == ["program", "control_bf16",
+                                          "fault_half_batch"]
+    n = small_cell.traffic["compare_rounds"]
+    for r in lines:
+        assert set(r["by_round"]) == {"gap", "dist"}
+        for name, values in r["by_round"].items():
+            assert len(values) == n and r[name] == max(values)
+            assert r["worst"][name].startswith("round ")
+        over = [r[k] > small_cell.limits[k] for k in ("gap", "dist")]
+        assert any(over) is (r["kind"] != "program"), r

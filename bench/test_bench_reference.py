@@ -15,9 +15,8 @@ def test_reference_agrees_with_the_program(config):
     cell = small(calibrate.cell_for(config, reduction(config)["mix"]))
     bench = harness.Bench(cell, 2**32 + 77, kernel_force=kernel_force(cell))
     assert bench.decomposition_mismatch() == 0
-    init, p_first, p_last, rounds = bench.compared_rounds(2)
-    r_first, r_last = harness.reference_params(cell, init, rounds)
-    got = compare.numbers(cell.model.leaves, init, (p_first, r_first),
-                          (p_last, r_last))
+    params, rounds = bench.compared_rounds(2)
+    refs = harness.reference_params(cell, params[:-1], rounds)
+    got = compare.numbers(cell.model.leaves, params[:-1], params[1:], refs)
     for name, (value, leaf) in got.items():
         assert value < 1e-3, (name, value, leaf)
