@@ -31,7 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.decomposition import Decomposition
-from repro.core.jit_utils import donate, donation_supported
+from repro.core.jit_utils import donate
 from repro.models import common, resnet as resnet_mod, vit as vit_mod
 from repro.obs import active as obs_active, annotate, span_if
 
@@ -472,6 +472,19 @@ def make_prefix_advance(runner: BlockRunner, lo: int, hi: int):
     return adv
 
 
+@jax.jit
+def block_carry(train):
+    """A block step's ``(train, vel)`` carry, built in one program per
+    block (``jit_block_carry``): a private copy of the split subtree and
+    zero velocity of the same shapes and dtypes.  ``jnp.copy`` inside
+    the program makes every ``train`` leaf a fresh output buffer, never
+    a forwarded input, so the step may donate the carry without
+    deleting ``params``' leaves.  ``jit`` specializes it on each block's
+    tree and shapes."""
+    return (jax.tree.map(jnp.copy, train),
+            jax.tree.map(jnp.zeros_like, train))
+
+
 def host_nbytes(tree) -> int:
     """Bytes of the host (numpy) arrays in ``tree``: what a device
     program called with it copies to the device."""
@@ -598,16 +611,13 @@ def client_update(runner: BlockRunner, params, dec: Decomposition, batches,
             zs = cache.prepare(params, batches, lo) if cache is not None \
                 else None
             with annotate("block.setup"):
-                train = runner.split(params, lo, hi)
-                # the FedProx anchor aliases the split views (cheap,
-                # never donated); the (train, vel) carry gets private
-                # buffers when the backend honors donation, so the step
-                # can update it in place without invalidating
-                # ``params``' leaves
-                anchor = jax.tree.map(jnp.asarray, train)
-                if donation_supported():
-                    train = jax.tree.map(jnp.copy, train)
-                vel = jax.tree.map(jnp.zeros_like, train)
+                # the FedProx anchor is the split views themselves
+                # (never donated); the donated (train, vel) carry is
+                # private, made by one program per block
+                anchor = runner.split(params, lo, hi)
+                train, vel = block_carry(anchor)
+                if obs is not None:
+                    obs.metrics.counter("block_carry").inc()
 
                 key = ("buffered" if cache is not None else "recompute",
                        lo, hi, j, lr, momentum, prox_mu)

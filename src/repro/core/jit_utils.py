@@ -12,8 +12,10 @@ so :func:`donate` gates on the backend to keep CPU runs clean.
 Callers that donate an argument must pass PRIVATE buffers: donating a
 view that aliases a live tree (e.g. ``runner.split``'s pass-through
 leaves aliasing the full params) would invalidate the original on the
-backends where donation is real.  See ``client_update`` in
-``core/blockwise.py`` for the pattern.
+backends where donation is real.  See ``block_carry`` in
+``core/blockwise.py`` for the pattern: ``client_update`` builds each
+block's donated carry in one jitted program whose outputs are fresh
+copies, never forwarded inputs.
 """
 from __future__ import annotations
 

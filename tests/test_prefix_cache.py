@@ -15,6 +15,8 @@ What must hold (see docs/prefix_cache.md):
   runtime, the budget check, and the systime latency model;
 * ``prox_mu > 0`` still anchors at the block-entry params.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -30,6 +32,7 @@ from repro.fl.data import build_federated
 from repro.fl.engine import RoundEngine, SimConfig, build_context
 from repro.fl.registry import get_strategy
 from repro.models import build, resnet, vit
+from repro.obs import Obs, activate
 
 
 # ------------------------------------------------------------------ helpers
@@ -249,3 +252,100 @@ def test_prox_anchors_correctly_with_cache():
 
     assert dist(p_prox, params) < dist(p_free, params)
     assert _max_diff(p_prox, p_prox_rec) <= 1e-6
+
+
+# ---------------------------------------------------------- block carry
+def _leaf_ptrs(tree):
+    return [leaf.unsafe_buffer_pointer() for leaf in jax.tree.leaves(tree)]
+
+
+@pytest.mark.parametrize("family", sorted(SETUPS))
+def test_block_carry_is_private(family, monkeypatch):
+    """The step donates the (train, vel) carry, so ``block_carry`` must
+    hand back fresh buffers: no output leaf may share a buffer with
+    ``params`` (or with another output), ``train`` equals the split
+    subtree, ``vel`` is zeros of its shapes and dtypes, and ``params``
+    stays readable after a whole ``client_update`` whose steps donate
+    their carry here too (XLA:CPU honors donation when asked)."""
+    _, runner, params, batches = SETUPS[family](jax.random.PRNGKey(7))
+    lo, hi = (1, 2) if runner.n_units >= 2 else (0, 1)
+    split = runner.split(params, lo, hi)
+    train, vel = blockwise.block_carry(split)
+
+    out_ptrs = _leaf_ptrs((train, vel))
+    assert len(set(out_ptrs)) == len(out_ptrs)
+    assert not set(out_ptrs) & set(_leaf_ptrs(params))
+    assert not set(out_ptrs) & set(_leaf_ptrs(split))
+    assert jax.tree.structure(train) == jax.tree.structure(split)
+    assert jax.tree.structure(vel) == jax.tree.structure(split)
+    for s, t, v in zip(jax.tree.leaves(split), jax.tree.leaves(train),
+                       jax.tree.leaves(vel)):
+        assert t.shape == s.shape and t.dtype == s.dtype
+        assert v.shape == s.shape and v.dtype == s.dtype
+        np.testing.assert_array_equal(np.asarray(t), np.asarray(s))
+        assert not np.asarray(v).any()
+
+    monkeypatch.setattr(blockwise, "donate", lambda *argnums: argnums)
+    blockwise.client_update(runner, params, _per_unit_dec(runner.n_units),
+                            batches, lr=0.05, step_cache={})
+    for leaf in jax.tree.leaves(params):
+        assert not leaf.is_deleted()
+        np.asarray(leaf)
+
+
+def _per_leaf_setup_update(runner, params, dec, batches, *, buffered, lr,
+                           momentum, local_steps, prox_mu):
+    """The block set-up as it was before ``block_carry``: split, one
+    eager copy and one ``zeros_like`` per trained leaf, then the same
+    step calls and merge as ``client_update``."""
+    cache = blockwise.PrefixCache(runner) if buffered else None
+    for j, (lo, hi) in enumerate(dec.blocks):
+        zs = cache.prepare(params, batches, lo) if buffered else None
+        train = runner.split(params, lo, hi)
+        anchor = jax.tree.map(jnp.asarray, train)
+        train = jax.tree.map(jnp.copy, train)
+        vel = jax.tree.map(jnp.zeros_like, train)
+        make = (blockwise.make_buffered_block_step if buffered
+                else blockwise.make_block_step)
+        step = make(runner, lo, hi, j, lr=lr, momentum=momentum,
+                    prox_mu=prox_mu)
+        for _ in range(local_steps):
+            for i, batch in enumerate(batches):
+                extra = (zs[i],) if buffered else ()
+                train, vel = step(params, train, vel, anchor, *extra, batch)
+        params = runner.merge(params, train, lo=lo, hi=hi)
+    return params
+
+
+@pytest.mark.parametrize("prox_mu", [0.0, 10.0])
+@pytest.mark.parametrize("buffered", [True, False])
+def test_block_carry_keeps_the_numbers(buffered, prox_mu):
+    """One carry program per block changes no number: ``client_update``
+    is bitwise equal to the per-leaf set-up loop it replaced."""
+    _, runner, params, batches = _resnet_setup(jax.random.PRNGKey(8))
+    dec = Decomposition(((0, 1), (1, 3)), 0, 0)
+    kw = dict(lr=0.05, momentum=0.9, local_steps=2, prox_mu=prox_mu)
+    got = blockwise.client_update(runner, params, dec, batches,
+                                  prefix_cache=buffered, **kw)
+    want = _per_leaf_setup_update(runner, params, dec, batches,
+                                  buffered=buffered, **kw)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+def test_block_carry_counter_once_per_block():
+    """With a capture active, ``block_carry`` counts one engagement per
+    block: five for a 5-block decomposition of one client."""
+    cfg = dataclasses.replace(rn_reduced(num_classes=4, image_size=16),
+                              stage_blocks=(2, 2, 2))
+    key = jax.random.PRNGKey(9)
+    params = resnet.init(key, cfg)
+    runner = blockwise.resnet_runner(cfg)
+    batches = [{"images": jax.random.normal(key, (4, 16, 16, 3)),
+                "labels": jnp.arange(4) % 4}]
+    dec = Decomposition(tuple((i, i + 1) for i in range(1, 6)), 1, 0)
+    obs = Obs()
+    with activate(obs):
+        blockwise.client_update(runner, params, dec, batches, lr=0.05)
+    assert obs.metrics.value("block_carry") == 5
